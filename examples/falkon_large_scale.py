@@ -19,6 +19,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import FalkonConfig, falkon_fit
 from repro.data.synthetic import KernelTask, make_kernel_dataset
 
@@ -42,6 +43,7 @@ def main():
         help="bf16 = bf16 inputs / fp32 accumulation",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     n = args.n
     M = args.centers or int(3 * n**0.5)

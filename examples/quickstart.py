@@ -5,10 +5,12 @@
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import FalkonConfig, falkon_fit, krr_direct
 
 
 def main():
+    enable_compile_cache()
     # data: y = sin(<w, x>) + noise
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
     n, d = 8_000, 10

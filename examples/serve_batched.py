@@ -11,6 +11,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, reduced_config
 from repro.models import decode_step, model_params, prefill
 
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
 
     import dataclasses
     cfg = reduced_config(args.arch)

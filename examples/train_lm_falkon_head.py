@@ -15,6 +15,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ModelConfig
 from repro.core import FalkonConfig, falkon_fit
 from repro.data import TokenStreamConfig, token_stream
@@ -48,6 +49,7 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     vocab = 512
     cfg = make_lm(args.d_model, args.layers, vocab)

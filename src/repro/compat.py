@@ -1,89 +1,29 @@
-"""JAX API compatibility shims (repo pin: jax==0.4.37).
+"""The JAX APIs this repo calls through one import (repo pin: jax==0.9.0).
 
-JAX moves fast; these wrappers give tests/benchmarks one stable import for
-APIs that have migrated across versions:
-
-* ``enable_x64``  — ``jax.enable_x64`` (newer) -> ``jax.experimental.enable_x64``
-                    (0.4.x). Context manager: ``with enable_x64(True): ...``
-* ``use_mesh``    — ``jax.sharding.use_mesh`` -> ``jax.set_mesh`` ->
-                    entering the ``Mesh`` object itself (0.4.x context
-                    manager). Context manager: ``with use_mesh(mesh): ...``
-* ``shard_map``   — ``jax.shard_map`` (newer) ->
-                    ``jax.experimental.shard_map.shard_map`` (0.4.x), with
-                    the replication-check kwarg (``check_rep`` ->
-                    ``check_vma`` rename) normalized away. This is the one
-                    entry point the distributed KernelOps backend uses.
+* ``enable_x64``  — ``jax.enable_x64``: ``with enable_x64(True): ...``
+* ``use_mesh``    — ``jax.set_mesh``: ``with use_mesh(mesh): ...``
+* ``shard_map``   — ``jax.shard_map`` with ``check_vma=False``: the
+                    per-shard functions this repo maps end in ``psum``
+                    reductions whose replication the static checker cannot
+                    always prove. This is the one entry point the
+                    distributed KernelOps backend uses.
+* ``cost_analysis_dict`` — ``compiled.cost_analysis()`` as a dict.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 
-
-def enable_x64(new_val: bool = True):
-    """Context manager enabling (or disabling) 64-bit types."""
-    fn = getattr(jax, "enable_x64", None)
-    if fn is not None:
-        return fn(new_val)
-    from jax.experimental import enable_x64 as _enable_x64
-    return _enable_x64(new_val)
-
-
-def use_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh."""
-    fn = getattr(jax.sharding, "use_mesh", None)
-    if fn is None:
-        fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh  # jax.sharding.Mesh is its own context manager on 0.4.x
+enable_x64 = jax.enable_x64
+use_mesh = jax.set_mesh
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map``/``jax.experimental.shard_map.shard_map`` across
-    jax versions (the module moved out of experimental after 0.4.x).
-
-    The per-shard functions this repo maps contain ``psum`` reductions whose
-    replication the static checker cannot always prove (the pre-refactor
-    wrapper already ran ``check_rep=False``), so the check is disabled under
-    whichever keyword spelling this jax uses (``check_rep`` on 0.4.x,
-    ``check_vma`` after the rename, or neither).
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    kwargs = {}
-    for kw in ("check_rep", "check_vma"):
-        if _accepts_kwarg(fn, kw):
-            kwargs[kw] = False
-            break
-    # A genuine TypeError from the call (bad specs, wrong arity) propagates
-    # untouched — the kwarg was chosen by signature, not by probing.
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def _accepts_kwarg(fn, name: str) -> bool:
-    """True if ``fn``'s signature names ``name`` as an explicit keyword (a
-    bare ``**kwargs`` does NOT count — passing the wrong rename through it
-    would fail later, far from here)."""
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return False
-    p = params.get(name)
-    return p is not None and p.kind in (
-        inspect.Parameter.POSITIONAL_OR_KEYWORD, inspect.Parameter.KEYWORD_ONLY
+    """``jax.shard_map`` with the replication check off (see above)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict across JAX versions.
-
-    Older versions returned a per-program list of dicts (often length 1);
-    newer ones return the dict directly (or None for trivial programs).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    """``compiled.cost_analysis()``, or ``{}`` for a trivial program."""
+    return dict(compiled.cost_analysis() or {})

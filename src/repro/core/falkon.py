@@ -190,6 +190,13 @@ class FalkonEstimator:
     # works regardless; partial_fit refuses with guidance.
     precond: Preconditioner | None = None
     lam: float | None = dataclasses.field(metadata=dict(static=True), default=None)
+    # The fit's data mesh: a mesh-fit estimator scores through
+    # DistributedOps too (row-sharded apply). Its centers/alpha live on the
+    # mesh, and a Pallas kernel fed mesh-placed arrays outside a shard_map
+    # cannot be partitioned by the TPU compiler.
+    mesh: Mesh | None = dataclasses.field(metadata=dict(static=True), default=None)
+    data_axes: tuple[str, ...] = dataclasses.field(
+        metadata=dict(static=True), default=("data",))
 
     @functools.cached_property
     def _ops(self) -> KernelOps:
@@ -198,12 +205,15 @@ class FalkonEstimator:
         # backend + resolved precision policy are built ONCE, not rebuilt
         # via get_ops on every predict() call. Both predict paths and the
         # serving layer route through this one object.
-        return get_ops(
+        ops = get_ops(
             self.ops_impl,
             self.kernel,
             block_size=self.block_size,
             precision=self.precision,
         )
+        if self.mesh is not None:
+            ops = DistributedOps(ops, self.mesh, self.data_axes)
+        return ops
 
     def build_knm_cache(self, X: Array, *, tier: str | None = None) -> KernelCache:
         """Materialize K(X, centers) once for REPEATED scoring of the same X.
@@ -737,6 +747,8 @@ def _stage_wrap(
         precision=config.precision,
         precond=precond,
         lam=None if lam is None else float(lam),
+        mesh=config.mesh,
+        data_axes=tuple(config.data_axes),
     )
 
 

@@ -27,6 +27,13 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+#: Matmul precision of the fit and scoring paths. XLA on a TPU runs an fp32
+#: matmul at DEFAULT precision as one bf16 pass (about 3 significant
+#: digits), which moves a Gram entry by ~1e-3 and breaks the fp32 policy's
+#: contract; HIGHEST contracts fp32 operands in full fp32 and leaves bf16
+#: operands (exact products) as they are.
+FP32 = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class KernelSpec:
@@ -84,7 +91,7 @@ def tile_eval(spec: KernelSpec, X: Array, Y: Array) -> Array:
     ``__call__`` reduces to (one matmul + VPU elementwise)."""
     a2 = jnp.sum(X * X, axis=-1, keepdims=True)            # (n, 1)
     b2 = jnp.sum(Y * Y, axis=-1, keepdims=True).T          # (1, m)
-    ab = X @ Y.T                                           # (n, m)  MXU
+    ab = jnp.matmul(X, Y.T, precision=FP32)                # (n, m)  MXU
     return tile_transform(ab, a2, b2, spec)
 
 
@@ -92,7 +99,7 @@ def _sqdist(X: Array, Y: Array) -> Array:
     """Pairwise squared euclidean distances, (n, d) x (m, d) -> (n, m)."""
     xx = jnp.sum(X * X, axis=-1, keepdims=True)
     yy = jnp.sum(Y * Y, axis=-1, keepdims=True).T
-    return jnp.maximum(xx + yy - 2.0 * (X @ Y.T), 0.0)
+    return jnp.maximum(xx + yy - 2.0 * jnp.matmul(X, Y.T, precision=FP32), 0.0)
 
 
 class KernelFn(Protocol):

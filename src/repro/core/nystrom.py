@@ -42,7 +42,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .kernels import KernelFn
+from .kernels import FP32, KernelFn
 
 Array = jax.Array
 
@@ -112,7 +112,7 @@ def build_leverage_pilot(
     def acc(carry, inp):
         xb, mb = inp
         Kb = kernel(xb, S) * mb[:, None]
-        return carry + Kb.T @ Kb, None
+        return carry + jnp.matmul(Kb.T, Kb, precision=FP32), None
 
     KSnKnS, _ = jax.lax.scan(acc, jnp.zeros((M0, M0), X.dtype), (Xb, mask))
     return LeveragePilot(S=S, KSS=KSS, KSnKnS=KSnKnS, indices=pilot_idx, n=n)

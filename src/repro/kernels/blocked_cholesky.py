@@ -26,10 +26,11 @@ Two interchangeable TILE ENGINES supply the three per-tile primitives:
 
 * ``"jnp"``    — BLAS-backed ``jnp.linalg.cholesky`` / ``solve_triangular`` /
                  matmul per tile. Default off-TPU; the numerical ground truth.
-* ``"pallas"`` — Pallas kernels (masked-column in-VMEM POTRF/TRSM, gridded
-                 SYRK update) following the ``repro.kernels.kernel_matvec``
-                 idioms. Default on TPU; interpret-mode on CPU for parity
-                 tests (``tile_impl="auto"`` picks per backend).
+* ``"pallas"`` — Pallas kernels (in-VMEM right-looking POTRF/TRSM on the
+                 VPU, gridded fp32 SYRK update on the MXU), sized so every
+                 block ``plan_factor`` picks fits VMEM. Default on TPU;
+                 interpret-mode on CPU for parity tests (``tile_impl="auto"``
+                 picks per backend).
 
 Tiles compute in float32 at minimum — the ``PrecisionPolicy`` ``cholesky``
 override's fp32 floor (quantized factors destabilize preconditioned CG; the
@@ -50,19 +51,21 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.kernels import FP32
+from repro.ops.base import FACTOR_CHUNK as CHUNK
+from repro.ops.base import _vmem_budget, factor_tile_vmem_bytes
+
+from .kernel_matvec import interpret_mode
 
 LANE = 128   # MXU/VREG lane width — last-dim tile alignment
-SUBLANE = 8  # fp32 sublane granularity
 
 TILE_IMPLS = ("auto", "jnp", "pallas")
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def resolve_tile_impl(tile_impl: str) -> str:
@@ -127,73 +130,108 @@ def _drop(stats: FactorStats, dev: jax.Array) -> None:
 # ---------------------------------------------------------------------------
 # Pallas tile kernels
 # ---------------------------------------------------------------------------
-# All three follow the kernel_matvec idioms: 2-D broadcasted_iota only (1-D
-# iota is banned on TPU), fori_loop carries instead of in-place mutation,
-# float32 (or float64 in interpret mode) math throughout the tile.
+# POTRF and TRSM are one right-looking elimination over the columns of a
+# VMEM tile. Step k scales column k by a pivot and subtracts its outer
+# product with a pivot row from the later columns, in fp32 on the VPU (no
+# MXU pass, so no matmul precision question). Dynamic indexing is along
+# sublanes only (``pl.ds`` rows); column k is read with a 2-D iota mask.
+# The tile is swept in CHUNK-row slabs so the temporaries stay (CHUNK, b)
+# whatever the tile height: the VMEM a tile needs is its operand buffers
+# plus a few slabs, which ``repro.ops.base.factor_tile_vmem_bytes``
+# charges and ``plan_factor`` caps the block with.
+
+def _eliminate(o_ref, k, pivot, rest):
+    """Column k of ``o_ref`` becomes x = o[:, k] / pivot; every column j
+    loses x * rest[j] (``rest`` is a (1, b) row, zero at j <= k)."""
+    rows, b = o_ref.shape
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
+
+    def slab(c, carry):
+        r0 = pl.multiple_of(c * CHUNK, CHUNK)
+        blk = o_ref[pl.ds(r0, CHUNK), :]
+        x = jnp.sum(jnp.where(cols == k, blk, 0.0), axis=1, keepdims=True) / pivot
+        o_ref[pl.ds(r0, CHUNK), :] = jnp.where(cols == k, x, blk - x * rest)
+        return carry
+
+    jax.lax.fori_loop(0, rows // CHUNK, slab, 0)
+
 
 def _potrf_kernel(a_ref, o_ref):
-    """In-VMEM unblocked Cholesky of one (b, b) tile: A = L L^T, emit L.
+    """Cholesky of one (b, b) SPD tile, A = L L^T; ``o_ref`` ends with L in
+    its lower triangle (the strict upper triangle is left stale).
 
-    Masked-column iteration: the loop carries the partial factor L and at
-    column j forms  v = A[:, j] - L[:, :j] @ L[j, :j]^T  using ``where``
-    masks built from 2-D iotas (no dynamic slicing inside the kernel), then
-    writes column j as [0; d; v_below / d] with d = sqrt(v_j)."""
-    A = a_ref[...]
-    b = A.shape[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    Step k: the pivot row k of the trailing matrix S gives d = sqrt(S_kk)
+    and, by symmetry, column k of L as the row S[k, k+1:] / d; eliminating
+    with pivot d writes L[:, k] and applies S -= l_k l_k^T."""
+    b = o_ref.shape[1]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
+    o_ref[...] = a_ref[...]
 
-    def body(j, L):
-        pref = jnp.where(cols < j, L, 0.0)            # L[:, :j], zero-extended
-        lj = jnp.sum(jnp.where(rows == j, pref, 0.0), axis=0,
-                     keepdims=True)                   # row j of the prefix
-        acol = jnp.sum(jnp.where(cols == j, A, 0.0), axis=1,
-                       keepdims=True)                 # A[:, j] as (b, 1)
-        v = acol - jnp.sum(pref * lj, axis=1, keepdims=True)
-        d = jnp.sum(jnp.where(rows[:, :1] == j, v, 0.0))  # v[j]
+    def step(k, carry):
+        row = o_ref[pl.ds(k, 1), :]
+        d = jnp.sum(jnp.where(cols == k, row, 0.0))
         # A non-positive pivot means the tile is not SPD (insufficient
         # jitter); propagate NaN so the failure is as observable as the
         # in-core jnp.linalg.cholesky path's, rather than clamping to a
         # finite garbage factor.
         d = jnp.sqrt(jnp.where(d > 0, d, jnp.nan))
-        colv = jnp.where(rows[:,:1] == j, d, jnp.where(rows[:,:1] > j, v / d, 0.0))
-        return jnp.where(cols == j, colv, L)
+        _eliminate(o_ref, k, d, jnp.where(cols > k, row / d, 0.0))
+        return carry
 
-    o_ref[...] = jax.lax.fori_loop(0, b, body, jnp.zeros_like(A))
-
-
-def _trsm_kernel(l_ref, a_ref, o_ref):
-    """One (bt, b) panel tile of  X = A L^{-T}  (i.e. solve X L^T = A).
-
-    Forward substitution over columns with the same iota-mask carry trick:
-    X[:, j] = (A[:, j] - X[:, :j] @ L[j, :j]^T) / L[j, j]."""
-    L = l_ref[...]
-    A = a_ref[...]
-    b = L.shape[0]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
-    xcols = jax.lax.broadcasted_iota(jnp.int32, A.shape, 1)
-
-    def body(j, X):
-        lj = jnp.sum(jnp.where(rows == j, jnp.where(cols < j, L, 0.0), 0.0),
-                     axis=0, keepdims=True)           # L[j, :j] as (1, b)
-        djj = jnp.sum(jnp.where((rows == j) & (cols == j), L, 0.0))
-        acol = jnp.sum(jnp.where(xcols == j, A, 0.0), axis=1, keepdims=True)
-        xpref = jnp.where(xcols < j, X, 0.0)
-        v = (acol - jnp.sum(xpref * lj, axis=1, keepdims=True)) / djj
-        return jnp.where(xcols == j, v, X)
-
-    o_ref[...] = jax.lax.fori_loop(0, b, body, jnp.zeros_like(A))
+    jax.lax.fori_loop(0, b, step, 0)
 
 
-def _update_kernel(c_ref, p_ref, q_ref, o_ref):
-    """One (bt, b) tile of the trailing update  C - P Q^T  (SYRK/GEMM)."""
-    o_ref[...] = c_ref[...] - jax.lax.dot_general(
+def _trsm_kernel(u_ref, a_ref, o_ref):
+    """One (bt, b) tile of X = A L^{-T}, given U = L^T: forward
+    substitution over the columns of X U = A, pivot U_jj, pivot row U[j]."""
+    b = o_ref.shape[1]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
+    o_ref[...] = a_ref[...]
+
+    def step(j, carry):
+        urow = u_ref[pl.ds(j, 1), :]
+        pivot = jnp.sum(jnp.where(cols == j, urow, 0.0))
+        _eliminate(o_ref, j, pivot, jnp.where(cols > j, urow, 0.0))
+        return carry
+
+    jax.lax.fori_loop(0, b, step, 0)
+
+
+def _update_kernel(c_ref, p_ref, q_ref, o_ref, acc_ref):
+    """One (UT, UN) tile of the trailing update  C - P Q^T  (SYRK/GEMM),
+    accumulated over UK-wide slices of the contraction in full fp32 on the
+    MXU."""
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        acc_ref[...] = c_ref[...]
+
+    acc_ref[...] -= jax.lax.dot_general(
         p_ref[...],
         q_ref[...],
         (((1,), (1,)), ((), ())),
-        preferred_element_type=c_ref.dtype,
+        precision=FP32,
+        preferred_element_type=acc_ref.dtype,
     )
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _flush():
+        o_ref[...] = acc_ref[...]
+
+
+#: Update-kernel tiles (rows, output columns, contraction slice): fixed, so
+#: its VMEM stays a few MB at any panel width.
+UT, UN, UK = 512, 256, 512
+
+
+def _row_tile(r: int, bp: int) -> int:
+    """Largest CHUNK-multiple TRSM row tile (<= 1024, <= the padded rows)
+    whose VMEM fits the budget; at least one CHUNK."""
+    bt = min(_round_up(r, CHUNK), 4 * CHUNK)
+    while bt > CHUNK and factor_tile_vmem_bytes("trsm", bp, bt) > _vmem_budget():
+        bt -= CHUNK
+    return bt
 
 
 def _pad_identity(A: jax.Array, bp: int) -> jax.Array:
@@ -208,37 +246,42 @@ def _pad_identity(A: jax.Array, bp: int) -> jax.Array:
     return jnp.where((r == c) & (r >= b), jnp.ones((), P.dtype), P)
 
 
+def _tile_width(b: int) -> int:
+    """Padded panel width: lane-aligned and a whole number of slabs."""
+    return _round_up(b, max(LANE, CHUNK))
+
+
 @partial(jax.jit, static_argnames=("interpret",))
 def _pallas_potrf(A, *, interpret: bool):
     b = A.shape[0]
-    bp = _round_up(b, LANE)
-    Ap = _pad_identity(A, bp)
+    bp = _tile_width(b)
     L = pl.pallas_call(
         _potrf_kernel,
         out_shape=jax.ShapeDtypeStruct((bp, bp), A.dtype),
         interpret=interpret,
-    )(Ap)
-    return L[:b,:b]
+    )(_pad_identity(A, bp))
+    return jnp.tril(L[:b,:b])
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def _pallas_trsm(L, A, *, interpret: bool):
     b = L.shape[0]
     r = A.shape[0]
-    bp = _round_up(b, LANE)
-    bt = min(_round_up(r, SUBLANE), 1024)
+    bp = _tile_width(b)
+    bt = _row_tile(r, bp)
     rp = _round_up(r, bt)
-    Lp = _pad_identity(jnp.tril(L), bp)
+    Up = _pad_identity(jnp.tril(L), bp).T
     Ap = jnp.pad(A, ((0, rp - r), (0, bp - b)))
     X = pl.pallas_call(
         _trsm_kernel,
         grid=(rp // bt,),
-        in_specs=[pl.BlockSpec((bp, bp), lambda i: (0, 0)),
+        in_specs=[pl.BlockSpec((bp, bp), lambda i: (0, 0),
+                               pipeline_mode=pl.Buffered(1)),
                   pl.BlockSpec((bt, bp), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bt, bp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, bp), A.dtype),
         interpret=interpret,
-    )(Lp, Ap)
+    )(Up, Ap)
     return X[:r,:b]
 
 
@@ -251,21 +294,22 @@ def _pallas_update(C, P, Q, *, interpret: bool):
     # silently truncates to the first bp columns.
     r, b = C.shape
     k = P.shape[1]
-    bp = _round_up(b, LANE)
-    kp = _round_up(k, LANE)
-    bt = min(_round_up(r, SUBLANE), 1024)
-    rp = _round_up(r, bt)
+    bn = min(_round_up(b, LANE), UN)
+    bk = min(_round_up(k, LANE), UK)
+    bt = min(_round_up(r, 8), UT)
+    bp, kp, rp = _round_up(b, bn), _round_up(k, bk), _round_up(r, bt)
     Cp = jnp.pad(C, ((0, rp - r), (0, bp - b)))
     Pp = jnp.pad(P, ((0, rp - r), (0, kp - k)))
     Qp = jnp.pad(Q, ((0, bp - Q.shape[0]), (0, kp - k)))
     O = pl.pallas_call(
         _update_kernel,
-        grid=(rp // bt,),
-        in_specs=[pl.BlockSpec((bt, bp), lambda i: (i, 0)),
-                  pl.BlockSpec((bt, kp), lambda i: (i, 0)),
-                  pl.BlockSpec((bp, kp), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((bt, bp), lambda i: (i, 0)),
+        grid=(rp // bt, bp // bn, kp // bk),
+        in_specs=[pl.BlockSpec((bt, bn), lambda i, j, kk: (i, j)),
+                  pl.BlockSpec((bt, bk), lambda i, j, kk: (i, kk)),
+                  pl.BlockSpec((bn, bk), lambda i, j, kk: (j, kk))],
+        out_specs=pl.BlockSpec((bt, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rp, bp), C.dtype),
+        scratch_shapes=[pltpu.VMEM((bt, bn), C.dtype)],
         interpret=interpret,
     )(Cp, Pp, Qp)
     return O[:r,:b]
@@ -287,7 +331,8 @@ def _jnp_trsm(L, A):
 @jax.jit
 def _jnp_update(C, P, Q):
     return C - jax.lax.dot_general(
-        P, Q, (((1,), (1,)), ((), ())), preferred_element_type=C.dtype
+        P, Q, (((1,), (1,)), ((), ())), precision=FP32,
+        preferred_element_type=C.dtype,
     )
 
 
@@ -295,7 +340,7 @@ def _engine(tile_impl: str):
     impl = resolve_tile_impl(tile_impl)
     if impl == "jnp":
         return _jnp_potrf, _jnp_trsm, _jnp_update
-    interp = _interpret()
+    interp = interpret_mode()
     return (
         partial(_pallas_potrf, interpret=interp),
         partial(_pallas_trsm, interpret=interp),
@@ -425,7 +470,8 @@ def blocked_syrk_tt(
             j0, j1 = j * block, min((j + 1) * block, M)
             S = _put(stats, T[j0:j1, i0:], dev_dt)
             D = jax.lax.dot_general(
-                R, S, (((1,), (1,)), ((), ())), preferred_element_type=dev_dt
+                R, S, (((1,), (1,)), ((), ())),
+                precision=FP32, preferred_element_type=dev_dt,
             )
             D.block_until_ready()
             stats.alloc(D.nbytes)

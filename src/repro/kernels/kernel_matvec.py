@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.kernels import KernelSpec, tile_transform
+from repro.core.kernels import FP32, KernelSpec, tile_transform
 
 Array = jax.Array
 
@@ -57,6 +57,12 @@ SUBLANE = 8  # fp32 sublane granularity
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: compiled by Mosaic on a
+    TPU, emulated in Python on every other backend (CPU tests)."""
+    return jax.default_backend() != "tpu"
 
 
 def _as_spec(kind: str, scale: float, spec: KernelSpec | None) -> KernelSpec:
@@ -111,8 +117,14 @@ def _tile(a, b, spec: KernelSpec) -> Array:
     bf = b.astype(jnp.float32)
     a2 = jnp.sum(af * af, axis=-1, keepdims=True)              # (bm, 1) VPU
     b2 = jnp.sum(bf * bf, axis=-1, keepdims=True).T            # (1, bn) VPU
+    # Mosaic contracts narrow (bf16) operands natively and refuses an fp32
+    # contract precision for them, wide ones need it (see FP32). DEFAULT is
+    # explicit so that jax.default_matmul_precision cannot override it.
+    wide = jnp.dtype(a.dtype).itemsize >= 4
     ab = jax.lax.dot_general(                                   # (bm, bn) MXU
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        a, b, (((1,), (1,)), ((), ())),
+        precision=FP32 if wide else jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
     return tile_transform(ab, a2, b2, spec)
 
 
@@ -160,7 +172,8 @@ def _kernel_matmul_kernel(
     k = _tile(a_ref[...], b_ref[...], spec) * bmask
     v = v_ref[...].astype(jnp.float32)
     delta = jax.lax.dot_general(                               # (bm, p) MXU
-        k, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        k, v, (((1,), (0,)), ((), ())), precision=FP32,
+        preferred_element_type=jnp.float32)
     if compensated:
         acc_ref[...], comp_ref[...] = _two_sum(acc_ref[...], comp_ref[...], delta)
     else:
@@ -322,7 +335,8 @@ def _fused_sweep_kernel(
     strip_ref[j] = k
     u = u_ref[...].astype(jnp.float32)                         # (bn, p)
     t_delta = jax.lax.dot_general(                             # (bm, p) MXU
-        k, u, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        k, u, (((1,), (0,)), ((), ())), precision=FP32,
+        preferred_element_type=jnp.float32)
     if compensated:
         t_ref[...], tc_ref[...] = _two_sum(t_ref[...], tc_ref[...], t_delta)
     else:
@@ -343,7 +357,7 @@ def _fused_sweep_kernel(
 
         def body(jj, _):
             delta = jax.lax.dot_general(                       # (bn, p) MXU
-                strip_ref[jj], t, (((0,), (0,)), ((), ())),
+                strip_ref[jj], t, (((0,), (0,)), ((), ())), precision=FP32,
                 preferred_element_type=jnp.float32)
             if compensated:
                 w_ref[jj], wc_ref[jj] = _two_sum(w_ref[jj], wc_ref[jj], delta)

@@ -23,16 +23,13 @@ import jax
 from repro.core.kernels import spec_of
 from .kernel_matvec import (
     fused_sweep_pallas,
+    interpret_mode,
     kernel_matmul_pallas,
     pairwise_kernel_pallas,
     sharded_sweep_pallas,
 )
 
 Array = jax.Array
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def fused_knm_matvec(
@@ -53,7 +50,7 @@ def fused_knm_matvec(
         v,
         spec=spec_of(kernel),
         block_m=min(block_size, 256),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
 
 
@@ -78,7 +75,7 @@ def sharded_knm_matvec(
         spec=spec_of(kernel),
         shard_m=shard_m,
         block_m=min(block_size, 256),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
 
 
@@ -98,12 +95,12 @@ def two_pass_knm_matvec(
     squeeze = u.ndim == 1
     u2 = u[:, None] if squeeze else u
     t = kernel_matmul_pallas(
-        X, C, u2, spec=spec, block_m=min(block_size, 256), interpret=_interpret()
+        X, C, u2, spec=spec, block_m=min(block_size, 256), interpret=interpret_mode()
     )
     if v is not None:
         t = t + (v[:, None] if squeeze else v)
     w = kernel_matmul_pallas(
-        C, X, t, spec=spec, block_m=min(block_size, 256), interpret=_interpret()
+        C, X, t, spec=spec, block_m=min(block_size, 256), interpret=interpret_mode()
     )
     return w[:, 0] if squeeze else w
 
@@ -121,7 +118,7 @@ def kernel_matmul(
         spec=spec_of(kernel),
         block_m=block_m,
         block_n=block_n,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
     return out[:, 0] if squeeze else out
 
@@ -136,5 +133,5 @@ def pairwise_kernel(
         spec=spec_of(kernel),
         block_m=block_m,
         block_n=block_n,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )

@@ -43,6 +43,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
+
 
 def serve_lm(args) -> None:
     from repro.configs import ARCH_IDS, get_config, reduced_config
@@ -210,6 +212,7 @@ def main():
                     help="fit via the host-streaming loader with this many "
                          "rows per chunk (0 = in-core fit)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.falkon:
         serve_falkon(args)

@@ -12,11 +12,10 @@ from __future__ import annotations
 import argparse
 import signal
 
-import jax
-
 from repro.configs import ARCH_IDS, get_config, reduced_config
 from repro.data import ShardedLoader, TokenStreamConfig, token_stream
 from repro.distributed.mesh import AxisRules
+from repro.launch.mesh import make_mesh
 from repro.train import TrainConfig, Trainer, TrainerConfig
 
 
@@ -41,7 +40,7 @@ def main():
     if args.mesh:
         dims = tuple(int(x) for x in args.mesh.split("x"))
         axes = ("pod", "data", "model")[-len(dims):]
-        mesh = jax.make_mesh(dims, axes)
+        mesh = make_mesh(dims, axes)
         rules = AxisRules(mesh=mesh, fsdp=cfg.fsdp)
 
     tcfg = TrainConfig(

@@ -395,6 +395,41 @@ _FACTOR_BLOCK_MIN = 256
 _FACTOR_BLOCK_MAX = 2048
 
 
+#: Rows per slab of the Pallas factor kernels' column elimination
+#: (``repro.kernels.blocked_cholesky``): their temporaries are (slab, b).
+FACTOR_CHUNK = 256
+
+
+def factor_tile_vmem_bytes(kind: str, b: int, rows: int = 0) -> int:
+    """VMEM a Pallas factor tile kernel holds at (padded) panel width ``b``
+    (fp32 tiles: the ``cholesky`` override's floor).
+
+    ``"potrf"``: the (b, b) input and output tiles (no grid). ``"trsm"``:
+    the single-buffered (b, b) U and the double-buffered (rows, b) A and X
+    tiles. Both add four (FACTOR_CHUNK, b) slabs of elimination
+    temporaries. (The trailing-update GEMM runs on fixed small tiles and
+    needs no model.)"""
+    slabs = 4 * FACTOR_CHUNK * b * 4
+    if kind == "potrf":
+        return 2 * b * b * 4 + slabs
+    if kind == "trsm":
+        return b * b * 4 + 4 * rows * b * 4 + slabs
+    raise ValueError(f"unknown factor tile kernel {kind!r}")
+
+
+def factor_block_cap() -> int:
+    """The widest lane-aligned panel whose Pallas POTRF tile (the one tile
+    held whole in VMEM) and a one-slab TRSM tile fit the VMEM budget."""
+    budget = _vmem_budget()
+    b = _FACTOR_BLOCK_MAX
+    while b > _FACTOR_BLOCK_MIN and (
+        factor_tile_vmem_bytes("potrf", b) > budget
+        or factor_tile_vmem_bytes("trsm", b, FACTOR_CHUNK) > budget
+    ):
+        b -= _FACTOR_BLOCK_MIN
+    return b
+
+
 def _factor_budget() -> int:
     mb = os.environ.get("REPRO_FACTOR_BUDGET_MB")
     return int(float(mb) * 2**20) if mb else DEFAULT_FACTOR_BUDGET
@@ -451,7 +486,9 @@ def plan_factor(
     bytes. When that exceeds the budget the factorization routes to the
     tiled right-looking blocked path, whose device working set is two
     (M, block) panels. ``block`` is sized so those panels fit the budget
-    (lane-aligned, clamped to [{_FACTOR_BLOCK_MIN}, {_FACTOR_BLOCK_MAX}]).
+    (lane-aligned, at least {_FACTOR_BLOCK_MIN}) and capped by
+    :func:`factor_block_cap`, so the Pallas tile kernels fit VMEM at any
+    block the planner picks.
 
     ``policy`` pins the in-tile compute dtype through the ``cholesky``
     per-buffer override — float32 by default even under the bf16 storage
@@ -469,10 +506,11 @@ def plan_factor(
     dense = M * M * itemsize
 
     if block is None:
-        # two (M, block) panels ~ one budget of device workspace
+        # two (M, block) panels ~ one budget of device workspace, and no
+        # wider than the Pallas tile kernels can hold in VMEM
         block = factor_budget // max(2 * M * itemsize, 1)
         block = (block // _FACTOR_BLOCK_MIN) * _FACTOR_BLOCK_MIN
-        block = max(_FACTOR_BLOCK_MIN, min(_FACTOR_BLOCK_MAX, block))
+        block = max(_FACTOR_BLOCK_MIN, min(factor_block_cap(), block))
     panel = 2 * block * M * itemsize
     base = dict(
         M=M,

@@ -43,6 +43,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+#: Full-fp32 contraction for fp32 operands (this package cannot import
+#: ``repro.core``; same value and reason as ``repro.core.kernels.FP32``).
+FP32 = jax.lax.Precision.HIGHEST
+
 Array = jax.Array
 
 
@@ -159,16 +163,17 @@ class GemmCacheMixin:
                 else:
                     kb, m = inp
                     Kf = kb.astype(cd) * m[:, None]
-                t = Kf @ u
+                t = jnp.matmul(Kf, u, precision=FP32)
             elif mb is None:
                 kb, vblk = inp
                 Kf = kb.astype(cd)
-                t = Kf @ u + vblk
+                t = jnp.matmul(Kf, u, precision=FP32) + vblk
             else:
                 kb, m, vblk = inp
                 Kf = kb.astype(cd) * m[:, None]
-                t = Kf @ u + vblk * (m[:, None] if vblk.ndim > 1 else m)
-            return Kf.T @ t
+                t = jnp.matmul(Kf, u, precision=FP32) + vblk * (
+                    m[:, None] if vblk.ndim > 1 else m)
+            return jnp.matmul(Kf.T, t, precision=FP32)
 
         if mb is None:
             xs = (Kb,) if v is None else (Kb, vb)
@@ -210,7 +215,7 @@ class GemmCacheMixin:
         Kb = K.reshape(rows // bs, bs, M)
 
         def body(kb):
-            return kb.astype(cd) @ u
+            return jnp.matmul(kb.astype(cd), u, precision=FP32)
 
         out = jax.lax.map(body, Kb)
         return out.reshape((rows,) + u.shape[1:])

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import OpsBase, SweepPlan, register_ops
-from .gemm import GemmCacheMixin, quantize_coeffs, quantize_storage
+from .gemm import FP32, GemmCacheMixin, quantize_coeffs, quantize_storage
 
 Array = jax.Array
 
@@ -100,14 +100,15 @@ class JnpKernelOps(GemmCacheMixin, OpsBase):
             if v is None:
                 xb, mb = inp
                 Kb = kernel(xb, C) * mb[:, None]          # mask padded rows
-                t = Kb @ u
+                t = jnp.matmul(Kb, u, precision=FP32)
             else:
                 xb, mb, vblk = inp
                 Kb = kernel(xb, C) * mb[:, None]
                 # Kb's zeroed rows already null padded contributions in
                 # Kb.T @ t; masking v too keeps t finite for arbitrary pads.
-                t = Kb @ u + vblk * (mb[:, None] if vblk.ndim > 1 else mb)
-            return Kb.T @ t
+                t = jnp.matmul(Kb, u, precision=FP32) + vblk * (
+                    mb[:, None] if vblk.ndim > 1 else mb)
+            return jnp.matmul(Kb.T, t, precision=FP32)
 
         xs = (Xb, mask) if v is None else (Xb, mask, vb)
         if pol.compensated:
@@ -139,7 +140,7 @@ class JnpKernelOps(GemmCacheMixin, OpsBase):
         kernel = self.kernel
 
         def body(xb):
-            return kernel(xb, C) @ u
+            return jnp.matmul(kernel(xb, C), u, precision=FP32)
 
         out = jax.lax.map(body, Xb)
         out = out.reshape((nb * Xb.shape[1],) + u.shape[1:])

@@ -46,10 +46,6 @@ from .gemm import GemmCacheMixin
 Array = jax.Array
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @register_ops("pallas")
 @dataclasses.dataclass(frozen=True)
 class PallasKernelOps(GemmCacheMixin, OpsBase):
@@ -124,7 +120,7 @@ class PallasKernelOps(GemmCacheMixin, OpsBase):
         fused kernel zeroes their t_i in VMEM; the sharded path zeroes the
         spilled t rows) — fixed-shape padded chunks sweep correctly."""
         from repro.kernels.kernel_matvec import (
-            fused_sweep_pallas, sharded_sweep_pallas
+            fused_sweep_pallas, interpret_mode, sharded_sweep_pallas
         )
         pol = self.policy
         X, C = self._inputs(X, C)
@@ -141,7 +137,7 @@ class PallasKernelOps(GemmCacheMixin, OpsBase):
                 row_mask=row_mask,
                 block_m=self._block_m,
                 compensated=pol.compensated,
-                interpret=_interpret(),
+                interpret=interpret_mode(),
             )
         warnings.warn(SweepPlanWarning(plan), stacklevel=2)
         # reduced-storage policies pin the HBM t spill to storage width and
@@ -163,7 +159,7 @@ class PallasKernelOps(GemmCacheMixin, OpsBase):
             compensated=pol.compensated,
             t_dtype=t_dt,
             out_dtype=out_dt,
-            interpret=_interpret(),
+            interpret=interpret_mode(),
         )
 
     def sweep_with_stats(
@@ -177,7 +173,7 @@ class PallasKernelOps(GemmCacheMixin, OpsBase):
         would route to an out-of-core path are rejected here rather than
         silently measuring a different implementation.
         """
-        from repro.kernels.kernel_matvec import fused_sweep_pallas
+        from repro.kernels.kernel_matvec import fused_sweep_pallas, interpret_mode
         pol = self.policy
         X, C = self._inputs(X, C)
         u, v = self._vectors(u, v)
@@ -197,12 +193,12 @@ class PallasKernelOps(GemmCacheMixin, OpsBase):
             spec=self._spec,
             block_m=self._block_m,
             compensated=pol.compensated,
-            interpret=_interpret(),
+            interpret=interpret_mode(),
             return_tile_count=True,
         )
 
     def apply(self, X: Array, C: Array, u: Array) -> Array:
-        from repro.kernels.kernel_matvec import kernel_matmul_pallas
+        from repro.kernels.kernel_matvec import interpret_mode, kernel_matmul_pallas
         pol = self.policy
         X, C = self._inputs(X, C)
         u, _ = self._vectors(u, None)
@@ -215,7 +211,7 @@ class PallasKernelOps(GemmCacheMixin, OpsBase):
             spec=self._spec,
             block_m=self._block_m,
             compensated=pol.compensated,
-            interpret=_interpret(),
+            interpret=interpret_mode(),
         )
         return out[:, 0] if squeeze else out
 
@@ -224,10 +220,10 @@ class PallasKernelOps(GemmCacheMixin, OpsBase):
         # policy): gram feeds the preconditioner's Cholesky (one-shot O(M^2)
         # work with no bandwidth win to harvest), and bf16 quantization can
         # push a borderline-PSD K_MM indefinite.
-        from repro.kernels.kernel_matvec import pairwise_kernel_pallas
+        from repro.kernels.kernel_matvec import interpret_mode, pairwise_kernel_pallas
         gt = jnp.dtype(self.policy.buffer_dtype("gram"))
         if jnp.dtype(A.dtype).itemsize < gt.itemsize:   # never downcast fp64
             A = A.astype(gt)
         if jnp.dtype(B.dtype).itemsize < gt.itemsize:
             B = B.astype(gt)
-        return pairwise_kernel_pallas(A, B, spec=self._spec, interpret=_interpret())
+        return pairwise_kernel_pallas(A, B, spec=self._spec, interpret=interpret_mode())

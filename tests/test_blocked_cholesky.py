@@ -140,6 +140,30 @@ def test_blocked_cholesky_pallas_indefinite_yields_nan():
     assert np.isnan(Tj).any(), "jnp engine should also surface NaNs"
 
 
+@pytest.mark.parametrize("M", [1280, 1100])
+def test_panel_cholesky_and_solves_match_direct(M):
+    """Past ``_DIRECT_MAX`` the in-core factor and its solves run as panel
+    loops (whole and ragged last panel here); they agree with XLA's direct
+    calls to fp32 rounding, for vector and matrix right-hand sides in both
+    orientations, under vmap, and surface an indefinite input as NaNs."""
+    from jax.scipy.linalg import solve_triangular
+    from repro.core.preconditioner import _DIRECT_MAX, cholesky_upper, tri_solve
+    assert M > _DIRECT_MAX
+    A = jnp.asarray(_spd(M, seed=5))
+    T = jnp.linalg.cholesky(A).T
+    assert _rel(cholesky_upper(A), T) < 1e-5
+    rng = np.random.default_rng(6)
+    for rhs in (rng.standard_normal(M), rng.standard_normal((M, 3))):
+        rhs = jnp.asarray(rhs, jnp.float32)
+        for trans in (False, True):
+            want = solve_triangular(T, rhs, lower=False, trans=int(trans))
+            assert _rel(tri_solve(T, rhs, trans), want) < 1e-5
+    batched = jax.vmap(cholesky_upper)(jnp.stack([A, 2 * A]))
+    assert _rel(batched[1], np.sqrt(2.0) * np.asarray(T)) < 1e-5
+    bad = A.at[M // 2, M // 2].set(-100.0)
+    assert np.isnan(np.asarray(cholesky_upper(bad))).any()
+
+
 def test_resolve_tile_impl():
     assert resolve_tile_impl("jnp") == "jnp"
     assert resolve_tile_impl("pallas") == "pallas"

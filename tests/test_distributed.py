@@ -17,6 +17,7 @@ def _run(body: str) -> str:
     code = textwrap.dedent(body)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"   # a child never competes for the chip
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(
         [sys.executable, "-c", code],
@@ -360,10 +361,11 @@ def test_mini_dryrun_train_and_decode():
         from repro.models import cache_pspecs, cache_specs, model_param_structs
         from repro.models.model import model_param_pspecs
         from repro.roofline.analysis import derive_roofline, memory_report
+        from repro.launch.mesh import make_mesh
         from repro.train.steps import (TrainConfig, batch_pspecs,
                                        make_serve_step, make_train_step,
                                        train_state_pspecs, train_state_structs)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                        is_leaf=lambda x: isinstance(x, P))
         for arch in ("jamba-1.5-large-398b", "granite-moe-3b-a800m"):
@@ -434,6 +436,7 @@ def test_elastic_restore_across_meshes():
         from repro.checkpoint import load_checkpoint, save_checkpoint, step_dir
         from repro.configs import reduced_config
         from repro.distributed.mesh import AxisRules, use_rules
+        from repro.launch.mesh import make_mesh
         from repro.train import TrainConfig, init_train_state, make_train_step
         from repro.train.steps import train_state_pspecs
 
@@ -447,7 +450,7 @@ def test_elastic_restore_across_meshes():
             lambda s: NamedSharding(mesh, s), t,
             is_leaf=lambda x: isinstance(x, P))
 
-        mesh_a = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh_a = make_mesh((2, 2, 2), ("pod", "data", "model"))
         rules_a = AxisRules(mesh=mesh_a)
         state = init_train_state(jax.random.PRNGKey(0), cfg, tcfg)
         with mesh_a, use_rules(rules_a):
@@ -457,7 +460,7 @@ def test_elastic_restore_across_meshes():
             save_checkpoint(step_dir(d, 1), state, 1, blocking=True)
 
             # restore onto a DIFFERENT mesh with its own shardings
-            mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+            mesh_b = make_mesh((4, 2), ("data", "model"))
             rules_b = AxisRules(mesh=mesh_b)
             shardings = named(mesh_b, train_state_pspecs(cfg, tcfg, rules_b))
             restored, stp = load_checkpoint(step_dir(d, 1), state,

@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from conftest import TEST_PRECISION, synthetic_regression
+from repro.compat import enable_x64
 from repro.core import (
     FalkonConfig,
     approximate_leverage_scores,
@@ -351,8 +352,38 @@ def test_path_fit_leverage_selection_shares_centers():
     assert all(est.centers is res.estimators[0].centers for est in res.estimators)
     for est in res.estimators:
         assert bool(jnp.all(jnp.isfinite(est.alpha)))
-    mse = float(jnp.mean((res.estimators[0].predict(X) - y) ** 2))
-    assert mse < 0.3
+    est = res.estimators[0]
+    mse = float(jnp.mean((est.predict(X) - y) ** 2))
+    J_t, bound = _cg_objective_bound(est, X, y, LAMS[0], cfg.iterations)
+    assert mse <= J_t <= bound, (mse, J_t, bound)
+
+
+def _cg_objective_bound(est, X, y, lam, t):
+    """(J(alpha_t), the bound CG guarantees for it), in float64.
+
+    The solver minimizes J(a) = |K_nM a - y|^2 / n + lam a^T R a with
+    R = D^-1 T^T T D^-1 (its own factor: K_MM plus the jitter), as
+    preconditioned CG on W = B^T H B. After t iterations from zero, CG's
+    error satisfies |e_t|_W <= 2 r^t |e_0|_W with r = (sqrt(k) - 1) /
+    (sqrt(k) + 1), k = cond(W); since J - J* = |e|_W^2 this gives
+    J(a_t) <= J* + 4 r^(2t) (J(0) - J*).
+    """
+    with enable_x64(True):
+        f64 = lambda a: None if a is None else jnp.asarray(np.asarray(a), jnp.float64)
+        P = jax.tree.map(f64, est.precond)
+        X64, y64, C64 = f64(X), f64(y), f64(est.centers)
+        n = X64.shape[0]
+        Knm = est.kernel(X64, C64)
+        Dinv = jnp.diag(1.0 / (P.D if P.D is not None else jnp.ones(C64.shape[0])))
+        R = Dinv @ P.T.T @ P.T @ Dinv
+        J = lambda a: float(jnp.mean((Knm @ a - y64) ** 2) + lam * a @ R @ a)
+        eye = jnp.eye(P.q)
+        W = P.left(Knm.T @ (Knm @ P.right(eye))) / n + P.ridge(eye, lam)
+        ev = jnp.linalg.eigvalsh((W + W.T) / 2)
+        r = (np.sqrt(ev[-1] / ev[0]) - 1) / (np.sqrt(ev[-1] / ev[0]) + 1)
+        J_star = J(jnp.linalg.solve(Knm.T @ Knm / n + lam * R, Knm.T @ y64 / n))
+        J_0 = J(jnp.zeros(C64.shape[0]))
+        return J(f64(est.alpha)), J_star + 4 * float(r) ** (2 * t) * (J_0 - J_star)
 
 
 # ---------------------------------------------------------------------------
