@@ -127,9 +127,11 @@ def _cg_init(matvec, b, x0, storage):
     return x, r, p, rs
 
 
-def _scan_driver(matvec, state, t, tol_sq, storage, res0):
+def _scan_driver(matvec, state, t, tol_sq, storage, res0, span, swept):
     """Fixed-length ``lax.scan`` loop — one static XLA program; converged
-    columns become masked no-ops (the dry-run wants the full-t shape)."""
+    columns become masked no-ops (the dry-run wants the full-t shape), so
+    all ``t`` matvecs run whatever the tolerance."""
+    span.set_metadata(sweeps=swept + t, iterations=t)
     def step(carry, _):
         x, r, p, rs, it = carry
         Ap = matvec(p)
@@ -147,20 +149,23 @@ def _scan_driver(matvec, state, t, tol_sq, storage, res0):
     )
 
 
-def _host_driver(matvec, state, t, tol_sq, storage, res0):
+def _host_driver(matvec, state, t, tol_sq, storage, res0, span, swept):
     """Python-level loop for host-streaming matvecs; stops early once every
     column has converged (each skipped iteration is a full data pass), so
-    ``residual_norms`` is truncated to ``iterations + 1`` entries."""
+    ``residual_norms`` is truncated to ``iterations + 1`` entries. Each
+    executed iteration is a ``falkon.cg.step`` span of one sweep."""
     x, r, p, rs = state
     residuals = [res0]
     it = 0
     for _ in range(t):
         if not bool(jnp.any(active_columns(rs, tol_sq))):
             break  # every column converged — skip the remaining data passes
-        Ap = matvec(p)
-        x, r, p, rs, _ = _masked_cg_update(x, r, p, rs, Ap, tol_sq, storage=storage)
-        residuals.append(jnp.sqrt(jnp.maximum(rs, 0.0))[None])
+        with jax.profiler.TraceAnnotation("falkon.cg.step", sweeps=1):
+            Ap = matvec(p)
+            x, r, p, rs, _ = _masked_cg_update(x, r, p, rs, Ap, tol_sq, storage=storage)
+            residuals.append(jnp.sqrt(jnp.maximum(rs, 0.0))[None])
         it += 1
+    span.set_metadata(sweeps=swept, iterations=it)
     return CGResult(
         x=x,
         residual_norms=jnp.concatenate(residuals, axis=0),
@@ -171,15 +176,22 @@ def _host_driver(matvec, state, t, tol_sq, storage, res0):
 def _cg_solve(matvec, b, t, tol, x0, storage_dtype, driver):
     """The one CG core both public drivers share: initialization, tolerance
     scaling and the ||b|| history head are computed identically, then the
-    ``driver`` runs the shared masked update in its loop style."""
-    storage = None if storage_dtype is None else jnp.dtype(storage_dtype)
-    state = _cg_init(matvec, b, x0, storage)
-    b_norm_sq = jnp.maximum(_col_dot(b, b), 1e-38)
-    tol_sq = (tol * tol) * b_norm_sq
-    # ||b|| leads the history; [None] gives the (1,)/(1, p) leading entry
-    # for single- and multi-rhs alike.
-    res0 = jnp.sqrt(jnp.maximum(_col_dot(b, b), 0.0))[None]
-    return driver(matvec, state, t, tol_sq, storage, res0)
+    ``driver`` runs the shared masked update in its loop style.
+
+    The solve is one ``falkon.cg`` profiler span whose ``sweeps`` count the
+    matvecs issued (one data sweep each in FALKON's W, the x0 residual's
+    included) and ``iterations`` the CG steps run. Under ``jit`` the span
+    records when the solve is traced, not when it runs."""
+    with jax.profiler.TraceAnnotation("falkon.cg") as span:
+        storage = None if storage_dtype is None else jnp.dtype(storage_dtype)
+        state = _cg_init(matvec, b, x0, storage)
+        b_norm_sq = jnp.maximum(_col_dot(b, b), 1e-38)
+        tol_sq = (tol * tol) * b_norm_sq
+        # ||b|| leads the history; [None] gives the (1,)/(1, p) leading entry
+        # for single- and multi-rhs alike.
+        res0 = jnp.sqrt(jnp.maximum(_col_dot(b, b), 0.0))[None]
+        swept = int(x0 is not None)
+        return driver(matvec, state, t, tol_sq, storage, res0, span, swept)
 
 
 def conjugate_gradient(

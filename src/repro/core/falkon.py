@@ -61,7 +61,8 @@ from .minibatch import (
 )
 from .nystrom import NystromCenters, select_centers
 from .preconditioner import (
-    Preconditioner, PreconditionerPath, make_preconditioner, make_preconditioner_path
+    Preconditioner, PreconditionerPath, make_preconditioner, make_preconditioner_path,
+    resolve_factor_plan
 )
 
 Array = jax.Array
@@ -73,6 +74,10 @@ CENTER_SELECTIONS = ("uniform", "leverage")
 # "device"/"host" force a residency tier (refusing, not spilling, when the
 # forced tier is unavailable — e.g. host under a mesh).
 KNM_CACHE_MODES = ("off", "auto", "device", "host")
+
+# Steps of each of the two power iterations behind ``estimate_cond``; each
+# takes one sweep per step plus one for its Rayleigh quotient.
+POWER_ITERS = 12
 
 _MATVEC_IMPL_DEPRECATION = (
     "matvec_impl is a deprecated alias of ops_impl (renamed in the KernelOps "
@@ -462,7 +467,10 @@ def falkon_solve(
             return ops.sweep(X, centers, zeros, y)
 
     W = _falkon_operator(matvec, precond, lam, n)
-    b = precond.left(rhs_sweep() / n)             # r = B^T z / n (Alg. 1)
+    # Profiler spans here and below record when this function runs eagerly
+    # (``falkon_fit``); under ``jit`` they record once, at trace time.
+    with jax.profiler.TraceAnnotation("falkon.rhs", sweeps=1):
+        b = precond.left(rhs_sweep() / n)         # r = B^T z / n (Alg. 1)
 
     host = cache is not None and cache.tier == "host"
     driver = conjugate_gradient_host if host else conjugate_gradient
@@ -480,10 +488,11 @@ def falkon_solve(
         )
 
     # Power-iteration estimate of cond(W) — cheap diagnostic for Thm 2.
-    # Its ~26 width-1 sweeps go through the SAME matvec closure as CG, so a
-    # cache serves them as GEMMs too (a host-tier cache cannot trace its
-    # tile loop under lax.scan — unroll the recurrence at the host level).
-    def power(mv, q, iters=12):
+    # Its 2 * (POWER_ITERS + 1) width-1 sweeps go through the SAME matvec
+    # closure as CG, so a cache serves them as GEMMs too (a host-tier cache
+    # cannot trace its tile loop under lax.scan — unroll the recurrence at
+    # the host level).
+    def power(mv, q, iters=POWER_ITERS):
         v = jnp.ones((q,), b.dtype) / jnp.sqrt(q)
         if host:
             for _ in range(iters):
@@ -497,11 +506,12 @@ def falkon_solve(
         return jnp.vdot(v, mv(v))
 
     q = precond.q
-    lam_max = power(lambda v: W(v.reshape((q,) + (1,) * (b.ndim - 1))).reshape(q), q)
-    lam_min = lam_max - power(
-        lambda v: lam_max * v - W(v.reshape((q,) + (1,) * (b.ndim - 1))).reshape(q), q
-    )
-    cond = jnp.abs(lam_max) / jnp.maximum(jnp.abs(lam_min), 1e-30)
+    with jax.profiler.TraceAnnotation("falkon.cond", sweeps=2 * (POWER_ITERS + 1)):
+        lam_max = power(lambda v: W(v.reshape((q,) + (1,) * (b.ndim - 1))).reshape(q), q)
+        lam_min = lam_max - power(
+            lambda v: lam_max * v - W(v.reshape((q,) + (1,) * (b.ndim - 1))).reshape(q), q
+        )
+        cond = jnp.abs(lam_max) / jnp.maximum(jnp.abs(lam_min), 1e-30)
 
     return FalkonState(
         centers=centers,
@@ -526,8 +536,9 @@ def _solve_path_core(
 ):
     """The shared lam-path solve: ONE RHS sweep + t stacked-matvec CG
     iterations serve all L systems; returns (CGResult, (M, L*p) alphas)."""
-    w0 = rhs_sweep() / n                  # K_nM^T y / n — lam-independent
-    b = precond.expand_rhs(w0)            # (q, L*p): per-system A^{-T} only
+    with jax.profiler.TraceAnnotation("falkon.rhs", sweeps=1):
+        w0 = rhs_sweep() / n              # K_nM^T y / n — lam-independent
+        b = precond.expand_rhs(w0)        # (q, L*p): per-system A^{-T} only
     W = _falkon_operator(matvec, precond, None, n)
     driver = conjugate_gradient_host if host else conjugate_gradient
     cg = driver(W, b, t, tol=tol, storage_dtype=storage)
@@ -611,20 +622,22 @@ def _stage_select(
     """Stage 1 — Nystrom center selection. ``lam`` overrides ``config.lam``
     for leverage scoring (the path fit scores at a grid-reference lam)."""
     M = min(config.num_centers, X.shape[0])
-    return select_centers(
-        key,
-        X,
-        M,
-        kernel=kernel,
-        lam=config.lam if lam is None else lam,
-        scheme=config.center_selection,
-        pilot_size=config.pilot_size,
-    )
+    with jax.profiler.TraceAnnotation("falkon.select"):
+        return select_centers(
+            key,
+            X,
+            M,
+            kernel=kernel,
+            lam=config.lam if lam is None else lam,
+            scheme=config.center_selection,
+            pilot_size=config.pilot_size,
+        )
 
 
 def _stage_gram(ops: KernelOps, centers: Array) -> Array:
     """Stage 2 — the M x M Gram block (the paper's memory budget)."""
-    return ops.gram(centers, centers)
+    with jax.profiler.TraceAnnotation("falkon.gram"):
+        return ops.gram(centers, centers)
 
 
 def _stage_cache(
@@ -667,7 +680,8 @@ def _stage_cache(
         warnings.warn(CachePlanWarning(plan), stacklevel=3)
     if plan.tier == "off":
         return None
-    return KernelCache(ops, X, centers, plan=plan)
+    with jax.profiler.TraceAnnotation("falkon.cache", tier=plan.tier):
+        return KernelCache(ops, X, centers, plan=plan)
 
 
 def _stage_precondition(
@@ -680,11 +694,16 @@ def _stage_precondition(
 ) -> "Preconditioner | PreconditionerPath":
     """Stage 3 — factorization. A scalar ``lam`` builds the single
     :class:`Preconditioner`; a grid builds the batched
-    :class:`PreconditionerPath` (shared T/Q/D, (L, q, q) A stack)."""
+    :class:`PreconditionerPath` (shared T/Q/D, (L, q, q) A stack). The
+    span's ``path`` is the factor route (``"incore"`` or ``"blocked"``)."""
     build = make_preconditioner if jnp.ndim(lam) == 0 else make_preconditioner_path
-    return build(
-        KMM, lam, n, D=D, jitter=config.jitter, rank_deficient=config.rank_deficient
-    )
+    with jax.profiler.TraceAnnotation("falkon.precondition") as span:
+        plan = resolve_factor_plan(KMM, None, config.rank_deficient)
+        span.set_metadata(path=plan.path)
+        return build(
+            KMM, lam, n, D=D, jitter=config.jitter,
+            rank_deficient=config.rank_deficient, factor_plan=plan,
+        )
 
 
 def _resolve_ops(
@@ -738,18 +757,19 @@ def _stage_wrap(
     can ``partial_fit`` later; every fit variant passes them (the path fit
     passes each system's single-lam view). Omitting them still yields a
     fully serving-capable estimator."""
-    return FalkonEstimator(
-        centers=centers,
-        alpha=alpha,
-        kernel=kernel,
-        block_size=config.block_size,
-        ops_impl=config.impl,
-        precision=config.precision,
-        precond=precond,
-        lam=None if lam is None else float(lam),
-        mesh=config.mesh,
-        data_axes=tuple(config.data_axes),
-    )
+    with jax.profiler.TraceAnnotation("falkon.wrap"):
+        return FalkonEstimator(
+            centers=centers,
+            alpha=alpha,
+            kernel=kernel,
+            block_size=config.block_size,
+            ops_impl=config.impl,
+            precision=config.precision,
+            precond=precond,
+            lam=None if lam is None else float(lam),
+            mesh=config.mesh,
+            data_axes=tuple(config.data_axes),
+        )
 
 
 def falkon_fit(
@@ -772,39 +792,44 @@ def falkon_fit(
     The K_MM Gram block, every CG sweep and the returned estimator's predict
     path all run on the backend named by ``config.ops_impl`` — or on ``ops``
     when given (the instrumentation seam: e.g. ``repro.ops.CountingOps``).
+
+    The fit is one ``falkon.fit`` profiler span holding one span per stage
+    (see docs/architecture.md, "Tracing").
     """
     if mesh is not None:
         config = dataclasses.replace(config, mesh=mesh, data_axes=tuple(data_axes))
-    kernel = config.make_kernel()
-    ops = _resolve_ops(config, kernel, ops)
-    dt = jnp.dtype(config.dtype)
-    X = X.astype(dt)
-    y = y.astype(dt)
-    n = X.shape[0]
+    n, d = X.shape
+    with jax.profiler.TraceAnnotation(
+            "falkon.fit", n=n, M=min(config.num_centers, n), d=d, t=config.iterations):
+        kernel = config.make_kernel()
+        ops = _resolve_ops(config, kernel, ops)
+        dt = jnp.dtype(config.dtype)
+        X = X.astype(dt)
+        y = y.astype(dt)
 
-    sel = _stage_select(key, X, config, kernel)
-    cache = _stage_cache(ops, X, sel.centers, config)
-    KMM = _stage_gram(ops, sel.centers)
-    precond = _stage_precondition(KMM, config.lam, n, config, D=sel.D)
+        sel = _stage_select(key, X, config, kernel)
+        cache = _stage_cache(ops, X, sel.centers, config)
+        KMM = _stage_gram(ops, sel.centers)
+        precond = _stage_precondition(KMM, config.lam, n, config, D=sel.D)
 
-    state = falkon_solve(
-        X,
-        y,
-        sel.centers,
-        precond,
-        kernel,
-        config.lam,
-        config.iterations,
-        block_size=config.block_size,
-        tol=config.tol,
-        estimate_cond=config.estimate_cond,
-        ops=ops,
-        cache=cache,
-    )
-    est = _stage_wrap(
-        sel.centers, state.alpha, kernel, config, precond=precond, lam=config.lam
-    )
-    return est, state
+        state = falkon_solve(
+            X,
+            y,
+            sel.centers,
+            precond,
+            kernel,
+            config.lam,
+            config.iterations,
+            block_size=config.block_size,
+            tol=config.tol,
+            estimate_cond=config.estimate_cond,
+            ops=ops,
+            cache=cache,
+        )
+        est = _stage_wrap(
+            sel.centers, state.alpha, kernel, config, precond=precond, lam=config.lam
+        )
+        return est, state
 
 
 def _score_path(
@@ -951,7 +976,8 @@ def falkon_solve_streaming(
         return streaming_sweep(jops, loader, centers, zeros, use_targets=True)
 
     W = _falkon_operator(matvec, precond, lam, n)
-    b = precond.left(rhs_sweep() / n)
+    with jax.profiler.TraceAnnotation("falkon.rhs", sweeps=1):
+        b = precond.left(rhs_sweep() / n)
     cg = conjugate_gradient_host(W, b, t, tol=tol, storage_dtype=_cg_storage(ops))
     alpha = precond.coeffs(cg.x)
     return FalkonState(

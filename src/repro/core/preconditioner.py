@@ -369,7 +369,7 @@ class PreconditionerPath:
 # ---------------------------------------------------------------------------
 # Factorization stages
 # ---------------------------------------------------------------------------
-def _resolve_factor_plan(KMM: Array, factor_plan, rank_deficient: bool):
+def resolve_factor_plan(KMM: Array, factor_plan, rank_deficient: bool):
     """Resolve the caller's ``factor_plan`` argument to a ``FactorPlan``.
 
     ``None`` auto-plans from the factor budget (``REPRO_FACTOR_BUDGET_MB``);
@@ -377,7 +377,8 @@ def _resolve_factor_plan(KMM: Array, factor_plan, rank_deficient: bool):
     taken as-is. A traced K_MM always lands in-core (the blocked path
     round-trips host memory, which a trace cannot do); a blocked plan with
     ``rank_deficient=True`` raises (see ``_shared_factor``); a blocked plan
-    on the normal path emits ``FactorPlanWarning``.
+    made here from ``None`` or a path name emits ``FactorPlanWarning`` (a
+    caller that passes a ``FactorPlan`` resolved it, and was warned, once).
     """
     # Lazy import: repro.ops.__init__ constructs backends that reach into
     # repro.core, so a module-level import here would be a cycle.
@@ -415,8 +416,42 @@ def _resolve_factor_plan(KMM: Array, factor_plan, rank_deficient: bool):
                 "eigendecomposition that this tiling cannot express. Use "
                 "the in-core path (raise REPRO_FACTOR_BUDGET_MB or pass "
                 "factor_plan='incore'), or drop rank_deficient.")
-        warnings.warn(FactorPlanWarning(plan), stacklevel=3)
+        if not isinstance(factor_plan, FactorPlan):
+            warnings.warn(FactorPlanWarning(plan), stacklevel=3)
     return plan
+
+
+# ---------------------------------------------------------------------------
+# The blocked route's host copies and host loops, each one profiler span
+# ---------------------------------------------------------------------------
+def _download(x: Array) -> np.ndarray:
+    """A whole device factor copied to a host working array (span
+    ``falkon.factor.download``). The wait for ``x`` to be computed stays
+    outside the span, so it times the copy alone."""
+    jax.block_until_ready(x)
+    with jax.profiler.TraceAnnotation("falkon.factor.download", bytes=x.nbytes):
+        return np.array(x)
+
+
+def _upload(h: np.ndarray, dt) -> Array:
+    """A whole host factor copied to the device (span ``falkon.factor.upload``).
+    The copy runs on after the call returns: the span holds the host's part."""
+    with jax.profiler.TraceAnnotation("falkon.factor.upload", bytes=h.nbytes):
+        return jnp.asarray(h, dt)
+
+
+def _blocked(name: str, loop, H: np.ndarray, block: int) -> np.ndarray:
+    """Run one host-blocked loop (``blocked_cholesky``/``blocked_syrk_tt``)
+    under span ``name``, its ``FactorStats`` set as the span's counts."""
+    from repro.kernels.blocked_cholesky import FactorStats
+    stats = FactorStats()
+    with jax.profiler.TraceAnnotation(name) as span:
+        out = loop(H, block, stats=stats)
+        span.set_metadata(
+            panels=stats.panels, tiles_updated=stats.tiles_updated,
+            bytes_transferred=stats.bytes_transferred,
+            peak_device_bytes=stats.peak_device_bytes)
+    return out
 
 
 def _shared_factor(
@@ -446,16 +481,16 @@ def _shared_factor(
 
     if plan is not None and plan.path == "blocked" and not rank_deficient:
         from repro.kernels.blocked_cholesky import blocked_cholesky, blocked_syrk_tt
-        Kh = np.array(KMM)                     # host working copy
+        Kh = _download(KMM)                    # host working copy
         if D is not None:
             Dh = np.array(D, dtype=Kh.dtype)
             Kh *= Dh[:, None]
             Kh *= Dh[None,:]
         eps = jitter if jitter is not None else float(jnp.finfo(dt).eps) * M
         Kh.flat[:: M + 1] += np.asarray(eps, Kh.dtype)
-        Th = blocked_cholesky(Kh, plan.block)
-        TTth = blocked_syrk_tt(Th, plan.block)
-        return jnp.asarray(Th, dt), None, jnp.asarray(TTth, dt), False
+        Th = _blocked("falkon.factor.cholesky", blocked_cholesky, Kh, plan.block)
+        TTth = _blocked("falkon.factor.syrk", blocked_syrk_tt, Th, plan.block)
+        return _upload(Th, dt), None, _upload(TTth, dt), False
 
     if D is not None:
         KMM = KMM * D[:, None] * D[None,:]
@@ -492,10 +527,11 @@ def _lam_factor(TTt: Array, lam, M: int, plan=None) -> Array:
             and not isinstance(TTt, jax.core.Tracer)
             and not isinstance(lam, jax.core.Tracer)):
         from repro.kernels.blocked_cholesky import blocked_cholesky
-        Bh = np.array(TTt)
+        Bh = _download(TTt)
         Bh /= M
         Bh.flat[:: Bh.shape[0] + 1] += np.asarray(float(lam), Bh.dtype)
-        return jnp.asarray(blocked_cholesky(Bh, plan.block), TTt.dtype)
+        return _upload(
+            _blocked("falkon.factor.cholesky", blocked_cholesky, Bh, plan.block), TTt.dtype)
     eye = jnp.eye(TTt.shape[0], dtype=TTt.dtype)
     return cholesky_upper(TTt / M + lam * eye)
 
@@ -526,7 +562,7 @@ def make_preconditioner(
     """
     M = KMM.shape[0]
     dt = KMM.dtype
-    plan = _resolve_factor_plan(KMM, factor_plan, rank_deficient)
+    plan = resolve_factor_plan(KMM, factor_plan, rank_deficient)
     T, Q, TTt, diag_T = _shared_factor(
         KMM, D, jitter, rank_deficient, rank_tol, plan=plan
     )
@@ -574,7 +610,7 @@ def make_preconditioner_path(
         raise ValueError(
             f"every lam in the path must be > 0, got {tuple(map(float, lams))}"
         )
-    plan = _resolve_factor_plan(KMM, factor_plan, rank_deficient)
+    plan = resolve_factor_plan(KMM, factor_plan, rank_deficient)
     T, Q, TTt, diag_T = _shared_factor(
         KMM, D, jitter, rank_deficient, rank_tol, plan=plan
     )
