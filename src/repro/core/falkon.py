@@ -695,11 +695,14 @@ def _stage_precondition(
     """Stage 3 — factorization. A scalar ``lam`` builds the single
     :class:`Preconditioner`; a grid builds the batched
     :class:`PreconditionerPath` (shared T/Q/D, (L, q, q) A stack). The
-    span's ``path`` is the factor route (``"incore"`` or ``"blocked"``)."""
+    span's ``path`` is the factor route (``"incore"`` or ``"blocked"``),
+    chosen against ``factor_budget_bytes``, derived from the devices'
+    ``free_bytes`` (-1: no reading)."""
     build = make_preconditioner if jnp.ndim(lam) == 0 else make_preconditioner_path
     with jax.profiler.TraceAnnotation("falkon.precondition") as span:
-        plan = resolve_factor_plan(KMM, None, config.rank_deficient)
-        span.set_metadata(path=plan.path)
+        plan = resolve_factor_plan(KMM, None, config.rank_deficient, systems=jnp.size(lam))
+        span.set_metadata(path=plan.path, factor_budget_bytes=plan.factor_budget_bytes,
+                          free_bytes=plan.free_bytes)
         return build(
             KMM, lam, n, D=D, jitter=config.jitter,
             rank_deficient=config.rank_deficient, factor_plan=plan,

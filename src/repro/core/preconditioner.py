@@ -41,10 +41,16 @@ factorization, never at the solves). Both builders route each O(M^3)
 Cholesky through ``repro.ops.plan_factor`` — the ``plan_sweep`` sibling for
 the preconditioner stack:
 
-* **incore** (dense factor fits ``REPRO_FACTOR_BUDGET_MB``, default 512 MB)
-  — one Cholesky on the device-resident matrix: ``jnp.linalg.cholesky`` up
-  to order ``_DIRECT_MAX``, a panel loop above it (:func:`cholesky_upper`;
-  the solves likewise, :func:`tri_solve`), so compile time stays flat in M.
+* **incore** (dense factor fits the factor budget) — one Cholesky on the
+  device-resident matrix: ``jnp.linalg.cholesky`` up to order
+  ``_DIRECT_MAX``, a panel loop above it (:func:`cholesky_upper`; the
+  solves likewise, :func:`tri_solve`), so compile time stays flat in M.
+  The budget is ``REPRO_FACTOR_BUDGET_MB`` when set; otherwise the least
+  free memory (``memory_stats()``: ``bytes_limit - bytes_in_use``) of the
+  devices K_MM lives on, over the dense factors this route holds at its
+  peak (``_INCORE_SHARED`` + ``_INCORE_PER_LAM`` per lam factor +
+  ``_INCORE_MARGIN``), and never below the 512 MB default, which also
+  stands where a device gives no reading (the CPU backend).
 * **blocked** (dense factor exceeds the budget) — the tiled right-looking
   out-of-core path (``repro.kernels.blocked_cholesky``): the matrix is
   factored from HOST memory in (b, b) tiles with only O(b * M) panel bytes
@@ -369,27 +375,65 @@ class PreconditionerPath:
 # ---------------------------------------------------------------------------
 # Factorization stages
 # ---------------------------------------------------------------------------
-def resolve_factor_plan(KMM: Array, factor_plan, rank_deficient: bool):
+#: The in-core route's peak, in dense (M, M) factors over the bytes in use
+#: once K_MM is computed. ``_shared_factor`` and ``_lam_factor`` run eagerly
+#: and dispatch runs ahead of the device, so each temporary (the identity
+#: and the jittered copy, each panel loop's carry and its three
+#: temporaries, ``tril``, slices, transposes, the ridge matrix) stays
+#: allocated until the ops that read it have run. Measured on a TPU v5e:
+#: 13.3 factors at M = 8192 and 16.3 at M = 12288 and 14080 for one lam;
+#: 28.3 for a grid of 4 at M = 8192, i.e. 5 more per further lam. The count
+#: covers them with room: 12 shared, 6 per lam factor built at once, 2 for
+#: XLA's own temporaries.
+_INCORE_SHARED = 12
+_INCORE_PER_LAM = 6
+_INCORE_MARGIN = 2
+
+
+def device_free_bytes(x) -> int | None:
+    """Least free memory (``bytes_limit - bytes_in_use``) over the devices
+    ``x`` lives on; None where a device gives no reading (the CPU backend).
+    Where there is a reading, a ``jax.Array`` is waited for first, so that
+    the temporaries of the ops computing it are freed, not counted in use."""
+    devices = getattr(getattr(x, "sharding", None), "device_set", ())
+    if not devices or not all("bytes_limit" in (d.memory_stats() or {}) for d in devices):
+        return None
+    if isinstance(x, jax.Array):
+        jax.block_until_ready(x)
+    return min(s["bytes_limit"] - s.get("bytes_in_use", 0)
+               for s in (d.memory_stats() for d in devices))
+
+
+def resolve_factor_plan(KMM: Array, factor_plan, rank_deficient: bool, systems: int = 1):
     """Resolve the caller's ``factor_plan`` argument to a ``FactorPlan``.
 
-    ``None`` auto-plans from the factor budget (``REPRO_FACTOR_BUDGET_MB``);
-    a path name ("incore"/"blocked") forces that route; a ``FactorPlan`` is
-    taken as-is. A traced K_MM always lands in-core (the blocked path
-    round-trips host memory, which a trace cannot do); a blocked plan with
-    ``rank_deficient=True`` raises (see ``_shared_factor``); a blocked plan
-    made here from ``None`` or a path name emits ``FactorPlanWarning`` (a
-    caller that passes a ``FactorPlan`` resolved it, and was warned, once).
+    ``None`` auto-plans from the factor budget: ``REPRO_FACTOR_BUDGET_MB``
+    when set, else the least free memory of the devices K_MM lives on over
+    the in-core route's peak for ``systems`` lam factors built at once
+    (``repro.ops.base.device_factor_budget``: never below the 512 MB
+    default, which also stands where there is no reading); the plan's
+    ``free_bytes`` keeps the reading. A path name ("incore"/"blocked")
+    forces that route; a ``FactorPlan`` is taken as-is. A traced K_MM
+    always lands in-core (the blocked path round-trips host memory, which a
+    trace cannot do); a blocked plan with ``rank_deficient=True`` raises
+    (see ``_shared_factor``); a blocked plan made here from ``None`` or a
+    path name emits ``FactorPlanWarning`` (a caller that passes a
+    ``FactorPlan`` resolved it, and was warned, once).
     """
     # Lazy import: repro.ops.__init__ constructs backends that reach into
     # repro.core, so a module-level import here would be a cycle.
-    from repro.ops.base import FACTOR_PATHS, FactorPlan, FactorPlanWarning, plan_factor
+    from repro.ops.base import (
+        FACTOR_PATHS, FactorPlan, FactorPlanWarning, device_factor_budget, plan_factor)
 
     M = KMM.shape[0]
     itemsize = max(jnp.dtype(KMM.dtype).itemsize, 4)
     if isinstance(factor_plan, FactorPlan):
         plan = factor_plan
     elif factor_plan is None:
-        plan = plan_factor(M, itemsize=itemsize)
+        free = None if isinstance(KMM, jax.core.Tracer) else device_free_bytes(KMM)
+        peak = _INCORE_SHARED + _INCORE_PER_LAM * systems + _INCORE_MARGIN
+        plan = plan_factor(M, itemsize=itemsize, factor_budget=device_factor_budget(free, peak))
+        plan = dataclasses.replace(plan, free_bytes=-1 if free is None else free)
     elif factor_plan in FACTOR_PATHS:
         # Force the named path by planning against a budget the dense
         # factor trivially fits (incore) or trivially exceeds (blocked).
@@ -554,8 +598,9 @@ def make_preconditioner(
     leverage-score sampling (None for uniform sampling).
 
     ``factor_plan`` routes the two Cholesky factorizations: ``None``
-    auto-plans in-core vs blocked from the dense-factor budget
-    (``REPRO_FACTOR_BUDGET_MB``), ``"incore"``/``"blocked"`` force a path,
+    auto-plans in-core vs blocked from the dense-factor budget (derived
+    from the devices' free memory, see :func:`resolve_factor_plan`),
+    ``"incore"``/``"blocked"`` force a path,
     and a ``repro.ops.FactorPlan`` is used as-is. See the module docstring
     ("Factor-path routing") for the contract; results are path-independent
     to ~1e-5 relative (tested), not bit-identical.
@@ -610,7 +655,7 @@ def make_preconditioner_path(
         raise ValueError(
             f"every lam in the path must be > 0, got {tuple(map(float, lams))}"
         )
-    plan = resolve_factor_plan(KMM, factor_plan, rank_deficient)
+    plan = resolve_factor_plan(KMM, factor_plan, rank_deficient, systems=lams.shape[0])
     T, Q, TTt, diag_T = _shared_factor(
         KMM, D, jitter, rank_deficient, rank_tol, plan=plan
     )

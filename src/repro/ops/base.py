@@ -65,9 +65,11 @@ carrying the full plan when it routes off the default path:
   (``REPRO_VMEM_BUDGET_MB``).
 * :func:`plan_factor` -> :class:`FactorPlan` (+ ``FactorPlanWarning``):
   routes the preconditioner's O(M^2) Cholesky factors incore -> blocked
-  against a device-memory budget (``REPRO_FACTOR_BUDGET_MB``, default
-  512 MB). The blocked path (``repro.kernels.blocked_cholesky``, consumed
-  by ``repro.core.preconditioner``) keeps the factor host-resident and
+  against a device-memory budget (``REPRO_FACTOR_BUDGET_MB``; else derived
+  from the devices' free memory by :func:`device_factor_budget`, 512 MB at
+  least and where there is no reading). The blocked path
+  (``repro.kernels.blocked_cholesky``, consumed by
+  ``repro.core.preconditioner``) keeps the factor host-resident and
   bounds peak device bytes at ``FactorPlan.device_ceiling_bytes`` =
   3 * 2 * block * M * itemsize — O(b*M), not O(M^2). ``tile_dtype`` honors
   the PrecisionPolicy ``cholesky`` override (float32 floor; see above).
@@ -378,15 +380,19 @@ class SweepPlanWarning(UserWarning):
 # ---------------------------------------------------------------------------
 FACTOR_PATHS = ("incore", "blocked")
 
-#: Default budget for a DENSE in-core Cholesky factor. FALKON's statistical
-#: optimality wants M ~ sqrt(n) Nystrom centers, and the preconditioner's
-#: O(M^2) factors are the first thing that stops fitting as M grows: a dense
-#: fp32 factor is 1 GB at M = 16384 and 40 GB at M = 10^5. Past this budget
-#: ``plan_factor`` routes to the blocked right-looking Cholesky
-#: (``repro.kernels.blocked_cholesky``), which keeps the matrix host-resident
-#: in (b, b) tiles and holds only O(b * M) panel bytes device-resident at any
-#: moment. Override per-process with ``REPRO_FACTOR_BUDGET_MB`` (the forcing
-#: knob tests use, mirroring ``REPRO_VMEM_BUDGET_MB``).
+#: Budget for a DENSE in-core Cholesky factor where no device reading is
+#: given. FALKON's statistical optimality wants M ~ sqrt(n) Nystrom centers,
+#: and the preconditioner's O(M^2) factors are the first thing that stops
+#: fitting as M grows: a dense fp32 factor is 1 GB at M = 16384 and 40 GB at
+#: M = 10^5. Past the budget ``plan_factor`` routes to the blocked
+#: right-looking Cholesky (``repro.kernels.blocked_cholesky``), which keeps
+#: the matrix host-resident in (b, b) tiles and holds only O(b * M) panel
+#: bytes device-resident at any moment. ``plan_factor`` itself plans against
+#: this number; the preconditioner (``resolve_factor_plan``) derives the
+#: budget from the free memory of the devices K_MM lives on, never below
+#: this floor, and keeps it where a device gives no reading (the CPU
+#: backend). ``REPRO_FACTOR_BUDGET_MB`` overrides both per process (the
+#: forcing knob tests use, mirroring ``REPRO_VMEM_BUDGET_MB``).
 DEFAULT_FACTOR_BUDGET = 512 * 2**20
 
 #: Blocked-path tile bounds: lane-aligned (multiples of _LANE*2 = 256) so the
@@ -435,6 +441,19 @@ def _factor_budget() -> int:
     return int(float(mb) * 2**20) if mb else DEFAULT_FACTOR_BUDGET
 
 
+def device_factor_budget(free_bytes: int | None, peak_factors: int) -> int:
+    """The dense-factor budget for a factorization on a device with
+    ``free_bytes`` free (None: no reading), whose in-core route holds
+    ``peak_factors`` dense factors at its peak: ``free_bytes //
+    peak_factors``, never below ``DEFAULT_FACTOR_BUDGET``, so a factor
+    routed in-core by the default stays in-core on any device. Without a
+    reading, or with ``REPRO_FACTOR_BUDGET_MB`` set, what
+    :func:`plan_factor` plans against by default."""
+    if free_bytes is None or os.environ.get("REPRO_FACTOR_BUDGET_MB"):
+        return _factor_budget()
+    return max(DEFAULT_FACTOR_BUDGET, free_bytes // peak_factors)
+
+
 @dataclasses.dataclass(frozen=True)
 class FactorPlan:
     """The Cholesky-path decision for one (M, M) factorization — the
@@ -461,6 +480,8 @@ class FactorPlan:
     tile_dtype: str = "float32"   # in-tile compute dtype (policy `cholesky`
     #                               override: fp32 floor even under bf16
     #                               storage — the PR 3 measured constraint)
+    free_bytes: int = -1          # least free bytes over K_MM's devices when
+    #                               the plan was resolved; -1: no reading
 
     @property
     def device_ceiling_bytes(self) -> int:

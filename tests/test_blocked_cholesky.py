@@ -1,7 +1,8 @@
 """The blocked out-of-core preconditioner path (ISSUE 7 tentpole).
 
 * ``plan_factor`` routing: budget model, block sizing, env override, the
-  structured ``FactorPlanWarning``.
+  structured ``FactorPlanWarning``; the budget ``resolve_factor_plan``
+  derives from the devices' ``memory_stats()`` (readings monkeypatched).
 * Blocked-vs-in-core factor parity (<= 1e-5 rel) on every registered
   kernel's K_MM, with and without the leverage-score diagonal D, for both
   ``make_preconditioner`` and ``make_preconditioner_path``.
@@ -19,13 +20,16 @@ the ``precond_blocked`` gate carry the same invariant in CI at smaller M.
 """
 import os
 import warnings
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import SingleDeviceSharding
 
 from repro.core import FalkonConfig, falkon_fit, make_kernel
+from repro.core import preconditioner as pc
 from repro.core.preconditioner import (make_preconditioner, make_preconditioner_path)
 from repro.kernels.blocked_cholesky import (
     FactorStats, blocked_cholesky, blocked_syrk_tt, resolve_tile_impl
@@ -33,6 +37,7 @@ from repro.kernels.blocked_cholesky import (
 from repro.ops import (
     FACTOR_PATHS, FactorPlan, FactorPlanWarning, get_ops, plan_factor
 )
+from repro.ops.base import DEFAULT_FACTOR_BUDGET
 
 KERNELS = [
     ("gaussian", dict(sigma=1.3)),
@@ -86,6 +91,137 @@ def test_plan_factor_x64_itemsize():
     p4 = plan_factor(8192, itemsize=4)
     p8 = plan_factor(8192, itemsize=8)
     assert p8.dense_bytes == 2 * p4.dense_bytes
+
+
+# ---------------------------------------------------------------------------
+# The factor budget derived from the devices' free memory
+# ---------------------------------------------------------------------------
+MS = 12288                          # MillionSongs' centers: 604 MB dense fp32
+GIB = 2**30
+PEAK = pc._INCORE_SHARED + pc._INCORE_PER_LAM + pc._INCORE_MARGIN
+
+
+def _reading(limit, in_use):
+    return dict(bytes_limit=limit, bytes_in_use=in_use, peak_bytes_in_use=in_use)
+
+
+def _kmm_on_device(monkeypatch, M, stats):
+    """An (M, M) fp32 K_MM on this process's device, whose ``memory_stats``
+    returns ``stats``. Shapes only: planning reads no entries."""
+    device = jax.devices()[0]
+    monkeypatch.setattr(type(device), "memory_stats", lambda self: stats)
+    return jax.ShapeDtypeStruct((M, M), jnp.float32, sharding=SingleDeviceSharding(device))
+
+
+class _Device:
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        return self.stats
+
+
+def test_free_memory_routes_millionsongs_factor_incore(monkeypatch):
+    """A v5e-like reading (16 GiB, 4.5 GB in use) holds M = 12288's in-core
+    peak: that factor, blocked under the 512 MB default, stays in-core."""
+    assert plan_factor(MS).path == "blocked"
+    KMM = _kmm_on_device(monkeypatch, MS, _reading(16 * GIB, 4_500_000_000))
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", FactorPlanWarning)
+        plan = pc.resolve_factor_plan(KMM, None, False)
+    free = 16 * GIB - 4_500_000_000
+    assert plan.path == "incore" and plan.free_bytes == free
+    assert plan.factor_budget_bytes == free // PEAK > plan.dense_bytes
+
+
+def test_free_memory_too_small_routes_blocked(monkeypatch):
+    """A reading one byte short of the in-core peak at M = 12288 (its budget
+    still above the floor) routes the factor blocked."""
+    dense = MS * MS * 4
+    KMM = _kmm_on_device(monkeypatch, MS, _reading(8 * GIB, 8 * GIB - PEAK * dense + 1))
+    with pytest.warns(FactorPlanWarning):
+        plan = pc.resolve_factor_plan(KMM, None, False)
+    assert plan.path == "blocked"
+    assert DEFAULT_FACTOR_BUDGET < plan.factor_budget_bytes == dense - 1
+
+
+@pytest.mark.parametrize("M", [300, 10000, MS])
+def test_no_reading_plans_as_before(monkeypatch, M):
+    """``memory_stats()`` giving None (the CPU backend) or no
+    ``bytes_limit`` leaves every plan exactly what the 512 MB default
+    gives."""
+    for stats in (None, dict(bytes_in_use=1)):
+        KMM = _kmm_on_device(monkeypatch, M, stats)
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", FactorPlanWarning)
+            plan = pc.resolve_factor_plan(KMM, None, False)
+        assert plan == plan_factor(M) and plan.free_bytes == -1
+    monkeypatch.undo()
+    real = _kernel_gram("gaussian", dict(sigma=1.3), M=64)
+    assert jax.devices()[0].memory_stats() is None          # this CPU backend
+    assert pc.resolve_factor_plan(real, None, False) == plan_factor(64)
+
+
+def test_env_budget_overrides_the_reading(monkeypatch):
+    KMM = _kmm_on_device(monkeypatch, MS, _reading(16 * GIB, GIB))
+    monkeypatch.setenv("REPRO_FACTOR_BUDGET_MB", "512")
+    with pytest.warns(FactorPlanWarning):
+        plan = pc.resolve_factor_plan(KMM, None, False)
+    assert plan.path == "blocked" and plan.factor_budget_bytes == 512 * 2**20
+    assert plan.free_bytes == 15 * GIB                      # still recorded
+    tiny = _kmm_on_device(monkeypatch, MS, _reading(GIB, GIB))
+    monkeypatch.setenv("REPRO_FACTOR_BUDGET_MB", "100000")
+    assert pc.resolve_factor_plan(tiny, None, False).path == "incore"
+
+
+def test_budget_floor_keeps_default_incore_factors(monkeypatch):
+    """Under a tiny reading the budget never drops below the default, so a
+    400 MB factor (SUSY's M = 1e4) stays in-core."""
+    KMM = _kmm_on_device(monkeypatch, 10000, _reading(GIB, GIB - 2**20))
+    plan = pc.resolve_factor_plan(KMM, None, False)
+    assert plan.path == "incore" and plan.factor_budget_bytes == DEFAULT_FACTOR_BUDGET
+    assert plan.free_bytes == 2**20
+
+
+def test_least_free_device_decides():
+    """K_MM replicated over two devices: the one with less free memory
+    sets the budget."""
+    roomy, tight = _reading(16 * GIB, GIB), _reading(16 * GIB, 15 * GIB)
+    KMM = SimpleNamespace(shape=(MS, MS), dtype=jnp.float32, sharding=SimpleNamespace(
+        device_set=[_Device(roomy), _Device(tight)]))
+    assert pc.device_free_bytes(KMM) == GIB
+    with pytest.warns(FactorPlanWarning):
+        plan = pc.resolve_factor_plan(KMM, None, False)
+    assert plan.path == "blocked" and plan.free_bytes == GIB
+    KMM.sharding.device_set[1] = _Device(roomy)
+    assert pc.resolve_factor_plan(KMM, None, False).path == "incore"
+
+
+def test_reading_waits_for_kmm(monkeypatch):
+    """With a reading, K_MM is waited for before the reading is taken, so
+    the temporaries of the ops computing it are not counted in use; without
+    one, nothing waits."""
+    waited = []
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: waited.append(x) or x)
+    KMM = jnp.eye(8)
+    device = jax.devices()[0]
+    monkeypatch.setattr(type(device), "memory_stats", lambda self: None)
+    assert pc.device_free_bytes(KMM) is None and waited == []
+    monkeypatch.setattr(type(device), "memory_stats", lambda self: _reading(GIB, 2**20))
+    assert pc.device_free_bytes(KMM) == GIB - 2**20 and waited == [KMM]
+
+
+def test_lam_grid_raises_the_incore_peak(monkeypatch):
+    """A path build holds every lam factor's ridge, carry and result at
+    once: the reading that takes one lam factor in-core sends eight
+    blocked."""
+    KMM = _kmm_on_device(monkeypatch, MS, _reading(16 * GIB, 4_500_000_000))
+    assert pc.resolve_factor_plan(KMM, None, False).path == "incore"
+    with pytest.warns(FactorPlanWarning):
+        plan = pc.resolve_factor_plan(KMM, None, False, systems=8)
+    peak = pc._INCORE_SHARED + 8 * pc._INCORE_PER_LAM + pc._INCORE_MARGIN
+    assert plan.path == "blocked"
+    assert plan.factor_budget_bytes == max(DEFAULT_FACTOR_BUDGET, plan.free_bytes // peak)
 
 
 # ---------------------------------------------------------------------------

@@ -3,8 +3,9 @@
 Each fit runs under ``jax.profiler.trace``; the ``falkon.*`` host events are
 read back from the trace with ``jax.profiler.ProfileData``. Checked: the
 spans nest as docs/architecture.md ("Tracing") lists them, their ``sweeps``
-counts add up to the sweeps a fit executes, the blocked factor's spans
-carry its ``FactorStats``, the host CG driver records one step span per
+counts add up to the sweeps a fit executes, the precondition span records
+the factor budget and the device reading it came from, the blocked
+factor's spans carry its ``FactorStats``, the host CG driver records one step span per
 executed iteration, and a traced fit computes the same bits as an untraced
 one.
 """
@@ -21,10 +22,12 @@ from repro.core import (
     FalkonConfig, MinibatchConfig, conjugate_gradient, conjugate_gradient_host,
     falkon_fit, falkon_fit_minibatch, falkon_fit_path, falkon_fit_streaming,
 )
+from repro.core import preconditioner as pc
 from repro.core.falkon import POWER_ITERS
 from repro.data import ArrayChunkSource
 from repro.kernels.blocked_cholesky import FactorStats, blocked_cholesky, blocked_syrk_tt
-from repro.ops import plan_factor
+from repro.ops import FactorPlanWarning, base, plan_factor
+from repro.ops.base import DEFAULT_FACTOR_BUDGET
 
 N, D, M, T = 600, 5, 64, 6
 FACTOR_COUNTS = ("panels", "tiles_updated", "bytes_transferred", "peak_device_bytes")
@@ -92,7 +95,8 @@ def test_fit_spans_nest_and_count_sweeps(tmp_path, estimate_cond):
     assert [s["name"] for s in rest] == stages           # in order, nothing else
     assert all(s["parent"] == "falkon.fit" for s in rest)
     by = {s["name"]: s["stats"] for s in rest}
-    assert by["falkon.precondition"] == dict(path="incore")
+    assert by["falkon.precondition"] == dict(
+        path="incore", factor_budget_bytes=DEFAULT_FACTOR_BUDGET, free_bytes=-1)
     assert by["falkon.rhs"] == dict(sweeps=1)
     assert by["falkon.cg"] == dict(sweeps=T, iterations=T)
     if estimate_cond:
@@ -112,7 +116,9 @@ def test_blocked_factor_spans_carry_factor_stats(tmp_path, monkeypatch):
     for s in spans:
         by.setdefault(s["name"], []).append(s)
     pre, = by["falkon.precondition"]
-    assert pre["stats"] == dict(path="blocked") and pre["parent"] == "falkon.fit"
+    assert pre["stats"] == dict(path="blocked", factor_budget_bytes=int(0.05 * 2**20),
+                                free_bytes=-1)
+    assert pre["parent"] == "falkon.fit"
     factor = [s for s in spans if s["name"].startswith("falkon.factor.")]
     assert all(s["parent"] == "falkon.precondition" for s in factor)
     # K_MM and T T^T go down; T, T T^T and A come back up, in this order
@@ -129,6 +135,26 @@ def test_blocked_factor_spans_carry_factor_stats(tmp_path, monkeypatch):
     assert [s["stats"] for s in by["falkon.factor.cholesky"]] == [want(chol)] * 2
     assert [s["stats"] for s in by["falkon.factor.syrk"]] == [want(syrk)]
     assert chol.panels == -(-m // block) > 1 and chol.tiles_updated > 0
+
+
+@pytest.mark.parametrize("in_use, path", [(0, "incore"), (2**30 - 2**20, "blocked")])
+def test_precondition_span_records_the_budget_and_reading(tmp_path, monkeypatch, in_use, path):
+    """With a device reading, ``falkon.precondition`` carries the route, the
+    budget derived from the reading and the reading itself. The budget's
+    floor is lowered here so that a CPU-sized K_MM can leave it."""
+    monkeypatch.setattr(base, "DEFAULT_FACTOR_BUDGET", 0)
+    monkeypatch.setattr(type(jax.devices()[0]), "memory_stats",
+                        lambda self: dict(bytes_limit=2**30, bytes_in_use=in_use))
+    X, y = _problem()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", FactorPlanWarning)
+        _, spans = _traced(tmp_path, lambda: falkon_fit(
+            jax.random.PRNGKey(1), X, y, _config(num_centers=300)))
+    pre, = [s for s in spans if s["name"] == "falkon.precondition"]
+    free = 2**30 - in_use
+    peak = pc._INCORE_SHARED + pc._INCORE_PER_LAM + pc._INCORE_MARGIN
+    assert pre["stats"] == dict(path=path, factor_budget_bytes=free // peak, free_bytes=free)
+    assert any(s["name"].startswith("falkon.factor.") for s in spans) == (path == "blocked")
 
 
 def _spd_system(q=40, seed=0):
